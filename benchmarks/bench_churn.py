@@ -40,6 +40,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 SCHEMES = ("lavea", "ibdash")
 RECOVERIES = ("fail_fast", "failover", "replan")
 GATED_SCHEME = "lavea"
@@ -271,6 +273,7 @@ def main() -> None:
     ap.add_argument("--check", default=None,
                     help="baseline json; exit 1 on recovery regression")
     args = ap.parse_args()
+    enable_compile_cache()
     report = full_report()
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

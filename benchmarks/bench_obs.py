@@ -38,6 +38,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 PLACE_B = 64                   # bench_place's middle batch size
 THROUGHPUT_FACTOR = 3.0        # wall-clock regression factor (CI standard)
 OVERHEAD_REPS = 3              # timed repetitions per tracing mode
@@ -256,6 +258,7 @@ def main() -> None:
                     help="baseline json; exit 1 on a validity failure or "
                          "throughput regression")
     args = ap.parse_args()
+    enable_compile_cache()
     report = full_report()
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

@@ -40,6 +40,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 BATCH_SIZES = (1, 64, 1000)
 REGRESSION_FACTOR = 2.0
 FLEET_SIZES = (1_000, 10_000, 100_000)
@@ -59,13 +61,33 @@ def _workload(B: int, seed: int = 1):
     ]
 
 
+def first_plan_difference(plans_a, plans_b):
+    """``None`` when the two plan lists place every task on the same
+    replicas with the same feasibility and estimated latency; otherwise a
+    description of the first task (or plan) that differs, with each side's
+    replicas as ``(device, est_total, pf)``."""
+    if len(plans_a) != len(plans_b):
+        return f"{len(plans_a)} plans vs {len(plans_b)}"
+    for i, (a, b) in enumerate(zip(plans_a, plans_b)):
+        pa, pb = a.placement, b.placement
+        for k, tp in pa.tasks.items():
+            other = pb.tasks.get(k)
+            reps_a = [(r.did, r.est_total, r.pred_fail) for r in tp.replicas]
+            reps_b = (None if other is None else
+                      [(r.did, r.est_total, r.pred_fail) for r in other.replicas])
+            if reps_a != reps_b:
+                return f"plan {i} ({pa.app_name}) task {k}: {reps_a} vs {reps_b}"
+        if (pa.feasible, pa.est_latency, len(pa.tasks)) != (
+                pb.feasible, pb.est_latency, len(pb.tasks)):
+            return (f"plan {i} ({pa.app_name}): feasible/est_latency/tasks "
+                    f"{pa.feasible}/{pa.est_latency}/{len(pa.tasks)} vs "
+                    f"{pb.feasible}/{pb.est_latency}/{len(pb.tasks)}")
+    return None
+
+
 def _same_plans(plans_a, plans_b) -> None:
-    for a, b in zip(plans_a, plans_b):
-        assert a.placement.feasible == b.placement.feasible
-        assert a.placement.est_latency == b.placement.est_latency
-        for k, tp in a.placement.tasks.items():
-            other = b.placement.tasks[k]
-            assert [r.did for r in tp.replicas] == [r.did for r in other.replicas]
+    diff = first_plan_difference(plans_a, plans_b)
+    assert diff is None, diff
 
 
 def measure(
@@ -132,6 +154,20 @@ def _forbid_dense(*_a, **_k):
     )
 
 
+def sweep_cluster(profile, n_devices: int, seed: int = 0):
+    """The fleet-sweep cluster: multi-tier, coarse T_alloc buckets (dt=0.5,
+    horizon=20) so the occupancy tensor stays a few hundred MB at 100k
+    devices, and the dense ``link_bw`` accessor tripwired."""
+    from repro.sim import make_cluster
+
+    cluster = make_cluster(
+        profile, scenario="multi_tier", n_devices=n_devices, seed=seed,
+        horizon=20.0, dt=0.5,
+    )
+    cluster.link_bw = _forbid_dense
+    return cluster
+
+
 def fleet_sweep(
     scheme: str = "ibdash",
     B: int = 16,
@@ -144,11 +180,11 @@ def fleet_sweep(
     Every cluster's dense ``link_bw`` accessor is replaced with a tripwire:
     the sweep COMPLETING is the proof that no ``(D, D)`` array was
     materialized anywhere in wave planning, at 100k devices included.
-    T_alloc uses coarse buckets (dt=0.5, horizon=20) so the occupancy
+    T_alloc uses coarse buckets (:func:`sweep_cluster`) so the occupancy
     tensor — the one intentionally O(D x N x buckets) structure — stays a
     few hundred MB at 100k devices."""
     from repro.api import orchestrate_batch
-    from repro.sim import SimConfig, make_cluster, make_profile
+    from repro.sim import SimConfig, make_profile
     from repro.sim.runner import policy_for
 
     profile = make_profile(seed=seed)
@@ -156,11 +192,7 @@ def fleet_sweep(
     apps = _workload(B)
     results = {}
     for D in sizes:
-        cluster = make_cluster(
-            profile, scenario="multi_tier", n_devices=D, seed=seed,
-            horizon=20.0, dt=0.5,
-        )
-        cluster.link_bw = _forbid_dense
+        cluster = sweep_cluster(profile, D, seed)
         pol = policy_for(scheme, profile, cfg)
         orchestrate_batch(apps, cluster, pol)     # warm the jitted kernels
         reps = 5 if D <= 10_000 else 2
@@ -276,6 +308,7 @@ def main() -> None:
     ap.add_argument("--check", default=None,
                     help="baseline json; exit 1 on >2x throughput regression")
     args = ap.parse_args()
+    enable_compile_cache()
     report = full_report()
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

@@ -35,6 +35,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 N_DEVICES = 100
 HORIZON = 45.0
 MODERATE_RATE = 60.0
@@ -71,21 +73,22 @@ def measure_generation() -> dict:
     return {"n": len(arr), "gen_per_sec": len(arr) / dt}
 
 
-def measure(profile, rate: float, admission: bool) -> dict:
+def measure(profile, rate: float, admission: bool,
+            n_devices: int = N_DEVICES, horizon: float = HORIZON) -> dict:
     from repro.api import Orchestrator, make_cluster, make_policy
     from repro.stream import AdmissionConfig, StreamingOrchestrator
     from repro.stream import poisson_arrivals
 
     cluster = make_cluster(
-        profile, scenario="stream", n_devices=N_DEVICES, seed=0,
-        horizon=HORIZON * 6.0 + 120.0,      # baseline backlog drains late
+        profile, scenario="stream", n_devices=n_devices, seed=0,
+        horizon=horizon * 6.0 + 120.0,      # baseline backlog drains late
     )
     orch = Orchestrator(
         cluster,
         make_policy("ibdash", alpha=0.5, beta=0.1, gamma=3,
                     lats_model=profile.lats_model),
     )
-    arrivals = poisson_arrivals(_streams(), rate, HORIZON, seed=7)
+    arrivals = poisson_arrivals(_streams(), rate, horizon, seed=7)
     service = StreamingOrchestrator(
         orch,
         admission=AdmissionConfig(queue_cap=QUEUE_CAP) if admission else None,
@@ -236,6 +239,7 @@ def main() -> None:
     ap.add_argument("--check", default=None,
                     help="baseline json; exit 1 on an SLO/shed regression")
     args = ap.parse_args()
+    enable_compile_cache()
     report = full_report()
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

@@ -31,6 +31,7 @@ from . import (
     bench_stream,
 )
 from .common import Ctx
+from repro.compile_cache import enable_compile_cache
 
 BENCHES = {
     "interference": bench_interference,   # Fig. 2 / Fig. 4
@@ -53,6 +54,7 @@ BENCHES = {
 
 def main() -> None:
     names = sys.argv[1:] or list(BENCHES)
+    enable_compile_cache()
     ctx = Ctx()
     print("name,value,derived")
     for name in names:

@@ -7,7 +7,7 @@ sizes, and checks the lowered program against the contracts the rest of
 the repo relies on:
 
   * **x64 bit-identity** (PR 2): the batched decision kernels run under
-    ``jax.experimental.enable_x64`` and must be float64 end to end — a
+    ``jax.enable_x64`` and must be float64 end to end — a
     stray ``float32`` constant or low-precision promotion silently breaks
     batched==scalar parity.  Any non-f64 floating value in the jaxpr of an
     ``x64=True`` kernel is flagged.
@@ -29,11 +29,10 @@ The audit runs from the ``kernel-hygiene`` lint rule's ``finalize``: the
 registered repo kernels come from :func:`builtin_targets`; test fixtures
 self-describe by exporting a module-level ``AUDIT_TARGETS`` list of
 :class:`KernelSpec` (the rule spots the assignment in the AST and imports
-the module by path).  Everything degrades to a no-op when jax is absent.
+the module by path).
 """
 from __future__ import annotations
 
-import contextlib
 from collections import Counter
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -42,22 +41,12 @@ __all__ = [
     "KernelSpec",
     "audit_spec",
     "builtin_targets",
-    "have_jax",
     "f64",
     "f32",
     "i64",
     "i32",
     "bools",
 ]
-
-
-def have_jax() -> bool:
-    try:
-        import jax  # noqa: F401
-
-        return True
-    except Exception:  # pragma: no cover - exercised on jax-less installs
-        return False
 
 
 # -- shape-spec helpers (ShapeDtypeStructs without importing jax at top) -------
@@ -263,12 +252,8 @@ def audit_spec(spec: KernelSpec) -> List[str]:
     traced: List[Tuple[Dict[str, int], Tuple[Any, ...], Any]] = []
     for point in spec.sweep:
         args = spec.build(point)
-        ctx = (
-            __import__("jax.experimental", fromlist=["enable_x64"])
-            .enable_x64() if spec.x64 else contextlib.nullcontext()
-        )
         try:
-            with ctx:
+            with jax.enable_x64(spec.x64):
                 closed = jax.make_jaxpr(
                     fn, static_argnums=spec.static_argnums
                 )(*args)

@@ -18,8 +18,6 @@ Audit targets:
     :class:`~repro.analysis.kernel_audit.KernelSpec` (how the golden
     fixtures describe themselves) — the module is imported by path at
     finalize time.
-
-The whole pass is a no-op when jax is not installed.
 """
 from __future__ import annotations
 
@@ -30,7 +28,7 @@ import os
 from typing import Iterator, List
 
 from ..framework import FileContext, Finding, ProjectContext, Rule, register_rule
-from ..kernel_audit import KernelSpec, audit_spec, builtin_targets, have_jax
+from ..kernel_audit import KernelSpec, audit_spec, builtin_targets
 
 _TARGETS_NAME = "AUDIT_TARGETS"
 
@@ -62,8 +60,6 @@ class KernelHygieneRule(Rule):
         return iter(())
 
     def finalize(self, project: ProjectContext) -> Iterator[Finding]:
-        if not have_jax():  # pragma: no cover - jax is baked into the image
-            return
         scanned = {fctx.path: fctx for fctx in project.files}
         for path, specs in builtin_targets().items():
             fctx = scanned.get(path)

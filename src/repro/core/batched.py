@@ -26,10 +26,10 @@ replicate loop (Algorithm 1 lines 30-41) as a ``lax.scan`` over the sorted
 candidate queue, vectorised over all B tasks and jitted with a static row
 count (B is padded to a bounded shape set — powers of two, then multiples
 of 1024 — so a 1000-instance burst compiles a handful of variants, not one
-per wave size); LAVEA's masked argmin; and the round-robin gather.  All kernels run under ``jax.experimental
-.enable_x64`` so their float64 arithmetic is **bit-identical** to the numpy
-scalar path — parity is asserted, not approximate.  When JAX is unavailable
-the same kernels fall back to equivalent vectorised numpy.
+per wave size); LAVEA's masked argmin; and the round-robin gather.  All
+kernels run under ``jax.enable_x64`` so their float64 arithmetic is
+**bit-identical** to the numpy scalar path — parity is asserted, not
+approximate.
 """
 from __future__ import annotations
 
@@ -44,7 +44,6 @@ __all__ = [
     "FleetSnapshot",
     "BatchedPolicyContext",
     "BatchedDecision",
-    "HAVE_JAX",
     "BATCH_KERNEL_MIN_ROWS",
     "TOPK_PRUNE_MIN_DEVICES",
     "ibdash_decide_batch",
@@ -428,13 +427,6 @@ class BatchedDecision:
 
 
 # -- JAX plumbing -------------------------------------------------------------
-try:  # the image bakes in jax; guard anyway so core stays importable without it
-    import jax as _jax_probe  # noqa: F401
-
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised on jax-less installs
-    HAVE_JAX = False
-
 _JAX_STATE: dict = {}
 
 
@@ -469,7 +461,6 @@ def _jax():
         return _JAX_STATE
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     _register_pytrees(jax)
 
@@ -550,7 +541,7 @@ def _jax():
 
     _JAX_STATE.update(
         jnp=jnp,
-        enable_x64=enable_x64,
+        enable_x64=jax.enable_x64,
         ibdash_scan_kernel=jax.jit(ibdash_scan_kernel),
         lavea_kernel=jax.jit(lavea_kernel),
         round_robin_kernel=jax.jit(round_robin_kernel),
@@ -610,7 +601,7 @@ def ibdash_decide_batch(
     """One fused call of the IBDASH score-and-replicate rule for B tasks.
 
     Bit-identical to looping the scalar rule: float64 arithmetic under
-    ``enable_x64``, stable sorts, and the same IEEE expressions per step.
+    ``jax.enable_x64``, stable sorts, and the same IEEE expressions per step.
     """
     B, D = total.shape
     n_feas = feasible.sum(axis=1)
@@ -626,10 +617,10 @@ def ibdash_decide_batch(
         order = np.argsort(masked, axis=1, kind="stable")[:, : n_scan + 1]
     s_total = np.take_along_axis(total, order, axis=1)
     s_pf = np.take_along_axis(pf, order, axis=1)
-    if HAVE_JAX and n_scan > 0:
+    if n_scan > 0:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"]():
+        with st["enable_x64"](True):
             accepts = st["ibdash_scan_kernel"](
                 _pad_rows(np.asarray(s_total, np.float64), n_pad, 1.0),
                 _pad_rows(np.asarray(s_pf, np.float64), n_pad, 0.0),
@@ -638,9 +629,7 @@ def ibdash_decide_batch(
             )
         accepts = np.asarray(accepts)[:B]
     else:
-        accepts = _ibdash_scan_numpy(
-            s_total, s_pf, n_feas, alpha, beta, gamma
-        )
+        accepts = np.zeros((B, 0), bool)
     n_extra = accepts.sum(axis=1)
     primary = order[:, 0]
     out: List[Tuple[int, ...]] = []
@@ -655,42 +644,16 @@ def ibdash_decide_batch(
     return out
 
 
-def _ibdash_scan_numpy(s_total, s_pf, n_feas, alpha, beta, gamma):
-    """Vectorised numpy twin of the jax scan (jax-less fallback)."""
-    B = s_total.shape[0]
-    n_scan = s_total.shape[1] - 1
-    best = s_total[:, 0]
-    l_ref = np.maximum(best, 1e-9)
-    comb = s_pf[:, 0].copy()
-    w_s = alpha * (best / l_ref) + (1 - alpha) * comb
-    active = np.ones(B, bool)
-    t_rep = np.zeros(B, np.int64)
-    accepts = np.zeros((B, n_scan), bool)
-    for qi in range(1, n_scan + 1):
-        cond = active & (comb >= beta) & (t_rep < gamma) & (qi < n_feas)
-        if not cond.any():
-            break
-        new_fail = comb * s_pf[:, qi]
-        w_new = alpha * (s_total[:, qi] / l_ref) + (1 - alpha) * new_fail
-        accept = cond & (w_new <= w_s)
-        comb = np.where(accept, new_fail, comb)
-        w_s = np.where(accept, w_new, w_s)
-        t_rep = t_rep + accept
-        accepts[:, qi - 1] = accept
-        active = accept
-    return accepts
-
-
 def lavea_decide_batch(
     queue_len: np.ndarray, feasible: np.ndarray
 ) -> List[Tuple[int, ...]]:
     """Fused SQLF for B tasks: masked argmin (first minimum, like the
     scalar ``ids[argmin(queue[ids])]``)."""
     n_feas = feasible.sum(axis=1)
-    if HAVE_JAX and queue_len.shape[0] >= BATCH_KERNEL_MIN_ROWS:
+    if queue_len.shape[0] >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
         n_pad = _padded(queue_len.shape[0]) - queue_len.shape[0]
-        with st["enable_x64"]():
+        with st["enable_x64"](True):
             picked = st["lavea_kernel"](
                 _pad_rows(np.asarray(queue_len, np.float64), n_pad, 0.0),
                 _pad_rows(np.asarray(feasible, bool), n_pad, True),
@@ -722,10 +685,10 @@ def tier_escalation_decide_batch(
     B, D = total.shape
     n_feas = feasible.sum(axis=1)
     n_tiers = int(tiers.max()) + 1 if tiers.size else 1
-    if HAVE_JAX and B >= BATCH_KERNEL_MIN_ROWS:
+    if B >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"]():
+        with st["enable_x64"](True):
             picked = st["tier_escalation_kernel"](
                 _pad_rows(np.asarray(total, np.float64), n_pad, 1.0),
                 _pad_rows(np.asarray(feasible, bool), n_pad, False),
@@ -762,10 +725,10 @@ def round_robin_decide_batch(
     nonempty = sizes > 0
     before = np.cumsum(nonempty) - nonempty          # non-empty rows before b
     targets = np.where(nonempty, (cursor + before) % np.maximum(sizes, 1), 0)
-    if HAVE_JAX and B >= BATCH_KERNEL_MIN_ROWS:
+    if B >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"]():
+        with st["enable_x64"](True):
             picked = st["round_robin_kernel"](
                 _pad_rows(np.asarray(feasible, bool), n_pad, True),
                 _pad_rows(np.asarray(targets, np.int64), n_pad, 0),

@@ -29,11 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
-# jax version compat: CompilerParams was TPUCompilerParams before 0.7
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 NEG_INF = -1e30
 
 
@@ -141,7 +136,7 @@ def flash_attention(
             pltpu.VMEM((block_q * g,), jnp.float32),
             pltpu.VMEM((block_q * g,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -38,10 +38,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["rwkv6_scan"]
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_ref, *,
             chunks: int):
@@ -135,7 +131,7 @@ def rwkv6_scan(
             jax.ShapeDtypeStruct((B, H, N, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -39,11 +39,6 @@ ALL_RULES = (
     "span-parity",
 )
 
-# the jaxpr auditor can only trace when jax is importable; everything else
-# in this suite is dependency-free
-NEEDS_JAX = ("kernel-hygiene",)
-
-
 def run_rule(rule, path, options=None, root=REPO):
     """Run ONE rule over one file/dir with everywhere-scoping."""
     cfg = LintConfig(
@@ -106,8 +101,6 @@ MIN_VIOLATIONS = {
 
 @pytest.mark.parametrize("rule", ALL_RULES)
 def test_rule_fires_on_violating_fixture(rule):
-    if rule in NEEDS_JAX:
-        pytest.importorskip("jax")
     path = FIXTURES / f"{FIXTURE_STEMS[rule]}_violation.py"
     report = run_rule(rule, path, FIXTURE_OPTIONS.get(rule))
     assert len(report.findings) >= MIN_VIOLATIONS[rule], report.findings
@@ -117,8 +110,6 @@ def test_rule_fires_on_violating_fixture(rule):
 
 @pytest.mark.parametrize("rule", ALL_RULES)
 def test_rule_silent_on_clean_fixture(rule):
-    if rule in NEEDS_JAX:
-        pytest.importorskip("jax")
     path = FIXTURES / f"{FIXTURE_STEMS[rule]}_clean.py"
     report = run_rule(rule, path, FIXTURE_OPTIONS.get(rule))
     assert report.findings == [], [f.format() for f in report.findings]

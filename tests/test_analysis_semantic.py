@@ -96,7 +96,6 @@ def test_batched_kernels_lower_once_across_fleet_sweep():
     lowers a bounded number of programs (one per padded wave bucket, not
     one per fleet size) across the D/B sweep — no shape-driven
     recompilation."""
-    pytest.importorskip("jax")
     from repro.analysis.kernel_audit import audit_spec, builtin_targets
 
     specs = builtin_targets()["src/repro/core/batched.py"]
@@ -111,7 +110,6 @@ def test_batched_kernels_lower_once_across_fleet_sweep():
 def test_auditor_counts_distinct_lowerings(tmp_path):
     """A kernel traced at unpadded sizes B in {8, 9, 10} must be reported
     as 3 distinct programs against an expectation of 1."""
-    pytest.importorskip("jax")
     from repro.analysis.kernel_audit import KernelSpec, audit_spec, f64
 
     def load():

@@ -122,7 +122,8 @@ def test_fleet_snapshot_shapes_and_values():
 
 
 def test_fleet_snapshot_is_a_pytree():
-    jax = pytest.importorskip("jax")
+    import jax
+
     from repro.core.batched import _jax
 
     _jax()  # registers the pytree nodes
@@ -288,8 +289,6 @@ def test_batch_kernel_path_used_for_big_pools(monkeypatch):
     (guard against silently always taking the scalar fallback)."""
     from repro.core import batched as bt
 
-    if not bt.HAVE_JAX:
-        pytest.skip("jax not installed")
     calls = []
     orig = bt.ibdash_decide_batch
 
