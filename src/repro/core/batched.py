@@ -39,6 +39,8 @@ from typing import List, Tuple
 
 import numpy as np
 
+from ..obs import hostspans
+
 __all__ = [
     "FLEET_SNAPSHOT_SCHEMA",
     "FleetSnapshot",
@@ -610,24 +612,26 @@ def ibdash_decide_batch(
     # with infeasible devices pushed to +inf.  Only the first n_scan + 1
     # entries are reachable, so the rest of the permutation is discarded —
     # and on big fleets never even computed (partial selection, same order).
-    masked = np.where(feasible, total, np.inf)
-    if D > TOPK_PRUNE_MIN_DEVICES and n_scan + 1 < D:
-        order = _topk_stable(masked, n_scan + 1)
-    else:
-        order = np.argsort(masked, axis=1, kind="stable")[:, : n_scan + 1]
-    s_total = np.take_along_axis(total, order, axis=1)
-    s_pf = np.take_along_axis(pf, order, axis=1)
+    with hostspans.span("policy.select", G=B, D=D, n_scan=n_scan):
+        masked = np.where(feasible, total, np.inf)
+        if D > TOPK_PRUNE_MIN_DEVICES and n_scan + 1 < D:
+            order = _topk_stable(masked, n_scan + 1)
+        else:
+            order = np.argsort(masked, axis=1, kind="stable")[:, : n_scan + 1]
+        s_total = np.take_along_axis(total, order, axis=1)
+        s_pf = np.take_along_axis(pf, order, axis=1)
     if n_scan > 0:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"](True):
+        with (hostspans.span("policy.kernel", G=B, rows=B + n_pad),
+              st["enable_x64"](True)):
             accepts = st["ibdash_scan_kernel"](
                 _pad_rows(np.asarray(s_total, np.float64), n_pad, 1.0),
                 _pad_rows(np.asarray(s_pf, np.float64), n_pad, 0.0),
                 _pad_rows(np.asarray(n_feas, np.int64), n_pad, D),
                 float(alpha), float(beta), int(gamma),
             )
-        accepts = np.asarray(accepts)[:B]
+            accepts = np.asarray(accepts)[:B]
     else:
         accepts = np.zeros((B, 0), bool)
     n_extra = accepts.sum(axis=1)
@@ -652,13 +656,15 @@ def lavea_decide_batch(
     n_feas = feasible.sum(axis=1)
     if queue_len.shape[0] >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
-        n_pad = _padded(queue_len.shape[0]) - queue_len.shape[0]
-        with st["enable_x64"](True):
+        B = queue_len.shape[0]
+        n_pad = _padded(B) - B
+        with (hostspans.span("policy.kernel", G=B, rows=B + n_pad),
+              st["enable_x64"](True)):
             picked = st["lavea_kernel"](
                 _pad_rows(np.asarray(queue_len, np.float64), n_pad, 0.0),
                 _pad_rows(np.asarray(feasible, bool), n_pad, True),
             )
-        picked = np.asarray(picked)[: queue_len.shape[0]]
+            picked = np.asarray(picked)[:B]
     else:
         masked = np.where(feasible, queue_len, np.inf)
         picked = np.argmin(masked, axis=1)
@@ -688,7 +694,8 @@ def tier_escalation_decide_batch(
     if B >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"](True):
+        with (hostspans.span("policy.kernel", G=B, rows=B + n_pad),
+              st["enable_x64"](True)):
             picked = st["tier_escalation_kernel"](
                 _pad_rows(np.asarray(total, np.float64), n_pad, 1.0),
                 _pad_rows(np.asarray(feasible, bool), n_pad, False),
@@ -696,7 +703,7 @@ def tier_escalation_decide_batch(
                 float(budget),
                 n_tiers,
             )
-        picked = np.asarray(picked)[:B]
+            picked = np.asarray(picked)[:B]
     else:
         rows = np.arange(B)
         picked = np.zeros(B, np.int64)
@@ -728,12 +735,13 @@ def round_robin_decide_batch(
     if B >= BATCH_KERNEL_MIN_ROWS:
         st = _jax()
         n_pad = _padded(B) - B
-        with st["enable_x64"](True):
+        with (hostspans.span("policy.kernel", G=B, rows=B + n_pad),
+              st["enable_x64"](True)):
             picked = st["round_robin_kernel"](
                 _pad_rows(np.asarray(feasible, bool), n_pad, True),
                 _pad_rows(np.asarray(targets, np.int64), n_pad, 0),
             )
-        picked = np.asarray(picked)[:B]
+            picked = np.asarray(picked)[:B]
     else:
         pos = np.cumsum(feasible, axis=1) - 1
         match = feasible & (pos == targets[:, None])

@@ -19,10 +19,12 @@ becomes HBM headroom, and task types become job classes.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -453,6 +455,32 @@ class ClusterState:
         b0 = self.bucket(t0)
         b1 = max(self.bucket(t1), b0 + 1)  # at least one bucket
         self.alloc[did, ttype, b0:b1] += w
+
+    @contextlib.contextmanager
+    def timing_writes(self) -> Iterator[List[int]]:
+        """Count and time every :meth:`add_interval` call, from any caller,
+        while the block is open: yields ``[calls, ns]``.  The method is
+        shadowed on this instance for the block alone, so T_alloc writes
+        outside it pay nothing."""
+        plain = self.add_interval
+        prior = vars(self).get("add_interval")
+        acc = [0, 0]
+        clock = time.perf_counter_ns
+
+        def add_interval(did, ttype, t0, t1, w=1.0):
+            c0 = clock()
+            plain(did, ttype, t0, t1, w)
+            acc[1] += clock() - c0
+            acc[0] += 1
+
+        self.add_interval = add_interval
+        try:
+            yield acc
+        finally:
+            if prior is None:
+                del self.add_interval
+            else:
+                self.add_interval = prior
 
     def _warn_horizon(self, t1: float) -> None:
         if self._horizon_warned:

@@ -60,6 +60,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import hostspans
 from .batched import BatchedPolicyContext, FleetSnapshot
 from .cluster import ClusterState
 from .dag import AppDAG
@@ -545,9 +546,28 @@ def orchestrate_batch(
     elif len(pinned) != len(apps):
         raise ValueError("apps and pinned must have equal length")
 
-    builder = _WaveContextBuilder(
-        cluster, now=min(times, default=float(now))
-    )
+    with hostspans.span("plan.wave", apps=len(apps)) as wave:
+        plans = _plan_wave(apps, cluster, policy, float(now), times, pinned,
+                           batched)
+        if wave.recording:
+            planned = sum(1 for p in plans if p.feasible)
+            wave.set(stages=max((a.n_stages for a in apps), default=0),
+                     planned=planned, infeasible=len(plans) - planned)
+    return plans
+
+
+def _plan_wave(
+    apps: Sequence[AppDAG],
+    cluster: ClusterState,
+    policy: Policy,
+    now: float,
+    times: Sequence[float],
+    pinned: Sequence[Optional[Dict[str, TaskPlacement]]],
+    batched: bool,
+) -> List[Plan]:
+    """The body of :func:`orchestrate_batch`, its arguments checked."""
+    with hostspans.span("plan.snapshot", D=cluster.n_devices):
+        builder = _WaveContextBuilder(cluster, now=min(times, default=now))
     bucket = cluster.bucket
     states = [
         _AppPlanState(
@@ -560,34 +580,39 @@ def orchestrate_batch(
     max_stages = max((st.n_stages for st in states), default=0)
 
     for s in range(max_stages):                         # line 3 (per wave)
-        rows: List[tuple] = []
-        for st in states:
-            if not st.alive or s >= st.n_stages:
-                continue
-            st.stage_latency = 0.0
-            t_start = st.arrival + st.stage_offset
-            bkt = bucket(t_start)
-            for tname in st.app.stages[s]:              # line 4
-                if tname not in st.pinned:
-                    rows.append((st, tname, t_start, bkt))
+        with hostspans.span("plan.screen") as screen:
+            rows: List[tuple] = []
+            for st in states:
+                if not st.alive or s >= st.n_stages:
+                    continue
+                st.stage_latency = 0.0
+                t_start = st.arrival + st.stage_offset
+                bkt = bucket(t_start)
+                for tname in st.app.stages[s]:          # line 4
+                    if tname not in st.pinned:
+                        rows.append((st, tname, t_start, bkt))
 
-        # Screen memory-infeasible rows before the policy sees the batch:
-        # the app dies at its first infeasible task and its later rows are
-        # excluded (stateful policies must not consume state for them).
-        kept: List[tuple] = []
-        for row in rows:
-            st = row[0]
-            if not st.alive:
-                continue
-            if not builder.feasible_any(st.app.tasks[row[1]]):
-                st.alive = False
-                st.infeasible_task = row[1]
-            else:
-                kept.append(row)
+            # Screen memory-infeasible rows before the policy sees the
+            # batch: the app dies at its first infeasible task and its later
+            # rows are excluded (stateful policies must not consume state
+            # for them).
+            kept: List[tuple] = []
+            for row in rows:
+                st = row[0]
+                if not st.alive:
+                    continue
+                if not builder.feasible_any(st.app.tasks[row[1]]):
+                    st.alive = False
+                    st.infeasible_task = row[1]
+                else:
+                    kept.append(row)
+            screen.set(rows_in=len(rows), rows_kept=len(kept))
         if not kept:
             continue
 
-        batch = builder.batch(kept)
+        with hostspans.span("plan.context", B=len(kept)) as context:
+            batch = builder.batch(kept)
+            context.set(G=batch.n_distinct)
         if batched:
             decisions = policy.decide_batch(batch).devices
         else:
@@ -597,63 +622,70 @@ def orchestrate_batch(
                 for b in range(batch.n_rows)
             )
 
-        # Bulk-extract the primary replica's estimate columns (one gather +
-        # one C-level tolist per tensor instead of 4B numpy scalar reads).
-        Bk = len(kept)
-        prim = np.fromiter(
-            (d[0] if d else 0 for d in decisions), np.int64, count=Bk
-        )
-        ex_p, up_p, tr_p, pf_p = batch.primary_estimates(prim)
-        ttypes_l = batch.ttypes.tolist()
-
-        # Apps that died during SCREENING still record their earlier kept
-        # rows (the scalar path places a stage's tasks one by one and keeps
-        # them when a later task turns out infeasible); apps that die here,
-        # on an empty DECISION, skip their remaining rows.
-        dead_in_record = set()
-        for b, row in enumerate(kept):
-            st = row[0]
-            if id(st) in dead_in_record:
-                continue                 # app died at an earlier row
-            devs = decisions[b]
-            if not devs:                 # e.g. the IBDASH avail_floor guard
-                st.alive = False
-                st.infeasible_task = row[1]
-                dead_in_record.add(id(st))
-                continue
-            replicas = [Replica(int(devs[0]), ex_p[b], up_p[b], tr_p[b], pf_p[b])]
-            for did in devs[1:]:
-                replicas.append(Replica(int(did), *batch.estimates_at(b, did)))
-            tp = TaskPlacement(
-                task=row[1],
-                ttype=ttypes_l[b],
-                replicas=replicas,
-                est_start=st.stage_offset,
-                est_latency=replicas[0].est_total,
+        with hostspans.span("plan.assemble", rows=len(kept)):
+            # Bulk-extract the primary replica's estimate columns (one
+            # gather + one C-level tolist per tensor instead of 4B numpy
+            # scalar reads).
+            Bk = len(kept)
+            prim = np.fromiter(
+                (d[0] if d else 0 for d in decisions), np.int64, count=Bk
             )
-            st.placements[row[1]] = tp                  # line 42
-            st.stage_latency = max(st.stage_latency, tp.est_latency)  # l.44
+            ex_p, up_p, tr_p, pf_p = batch.primary_estimates(prim)
+            ttypes_l = batch.ttypes.tolist()
 
-        for st in states:
-            if st.alive and s < st.n_stages:
-                st.stage_offset += st.stage_latency
+            # Apps that died during SCREENING still record their earlier
+            # kept rows (the scalar path places a stage's tasks one by one
+            # and keeps them when a later task turns out infeasible); apps
+            # that die here, on an empty DECISION, skip their remaining rows.
+            dead_in_record = set()
+            for b, row in enumerate(kept):
+                st = row[0]
+                if id(st) in dead_in_record:
+                    continue             # app died at an earlier row
+                devs = decisions[b]
+                if not devs:             # e.g. the IBDASH avail_floor guard
+                    st.alive = False
+                    st.infeasible_task = row[1]
+                    dead_in_record.add(id(st))
+                    continue
+                replicas = [Replica(int(devs[0]), ex_p[b], up_p[b], tr_p[b],
+                                    pf_p[b])]
+                for did in devs[1:]:
+                    replicas.append(
+                        Replica(int(did), *batch.estimates_at(b, did)))
+                tp = TaskPlacement(
+                    task=row[1],
+                    ttype=ttypes_l[b],
+                    replicas=replicas,
+                    est_start=st.stage_offset,
+                    est_latency=replicas[0].est_total,
+                )
+                st.placements[row[1]] = tp              # line 42
+                st.stage_latency = max(st.stage_latency,
+                                       tp.est_latency)  # line 44
+
+            for st in states:
+                if st.alive and s < st.n_stages:
+                    st.stage_offset += st.stage_latency
 
     # L(G) = sum of stage maxima (Eq. 3) == the final stage offset.  On a
     # replan, pinned tasks drop out: the plan holds only the newly placed
     # remainder (apply must not re-record the pinned tasks' occupancy).
-    return [
-        Plan(app=st.app, now=st.arrival, placement=Placement(
-            app_name=st.app.name,
-            tasks=(
-                {k: v for k, v in st.placements.items() if k not in st.pinned}
-                if st.pinned else st.placements
-            ),
-            est_latency=st.stage_offset if st.alive else 0.0,
-            feasible=st.alive,
-            infeasible_task=st.infeasible_task,
-        ))
-        for st in states
-    ]
+    with hostspans.span("plan.assemble", rows=len(states)):
+        return [
+            Plan(app=st.app, now=st.arrival, placement=Placement(
+                app_name=st.app.name,
+                tasks=(
+                    {k: v for k, v in st.placements.items()
+                     if k not in st.pinned}
+                    if st.pinned else st.placements
+                ),
+                est_latency=st.stage_offset if st.alive else 0.0,
+                feasible=st.alive,
+                infeasible_task=st.infeasible_task,
+            ))
+            for st in states
+        ]
 
 
 def orchestrate(

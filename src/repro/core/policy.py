@@ -37,6 +37,7 @@ from typing import Callable, Dict, Optional, Tuple, Type
 
 import numpy as np
 
+from ..obs import hostspans
 from .batched import (
     BatchedDecision,
     BatchedPolicyContext,
@@ -158,9 +159,12 @@ class Policy:
         raise NotImplementedError
 
     def decide_batch(self, batch: BatchedPolicyContext) -> BatchedDecision:
-        return BatchedDecision(devices=tuple(
-            self.decide(batch.row(b)).devices for b in range(batch.n_rows)
-        ))
+        with hostspans.span("policy.decide", B=batch.n_rows,
+                            G=batch.n_distinct, kernel=False):
+            return BatchedDecision(devices=tuple(
+                self.decide(batch.row(b)).devices
+                for b in range(batch.n_rows)
+            ))
 
 
 # -- registry -----------------------------------------------------------------
@@ -279,20 +283,24 @@ class IBDASHPolicy(Policy):
         Small pools take the scalar loop directly (jit dispatch would
         dominate)."""
         cfg = self.cfg
-        pf, feasible = self._batch_columns(batch)
-        if batch.n_distinct < BATCH_KERNEL_MIN_ROWS:
-            pool_dec = [
-                self._score(batch.total_pool[g], pf[g], feasible[g])
-                for g in range(batch.n_distinct)
-            ]
-        else:
-            pool_dec = ibdash_decide_batch(
-                batch.total_pool, pf, feasible,
-                cfg.alpha, cfg.beta, cfg.gamma,
-            )
-        return BatchedDecision(devices=tuple(
-            pool_dec[g] for g in batch.row_pool.tolist()
-        ))
+        G = batch.n_distinct
+        kernel = G >= BATCH_KERNEL_MIN_ROWS
+        with hostspans.span("policy.decide", B=batch.n_rows, G=G,
+                            kernel=kernel):
+            pf, feasible = self._batch_columns(batch)
+            if not kernel:
+                pool_dec = [
+                    self._score(batch.total_pool[g], pf[g], feasible[g])
+                    for g in range(G)
+                ]
+            else:
+                pool_dec = ibdash_decide_batch(
+                    batch.total_pool, pf, feasible,
+                    cfg.alpha, cfg.beta, cfg.gamma,
+                )
+            return BatchedDecision(devices=tuple(
+                pool_dec[g] for g in batch.row_pool.tolist()
+            ))
 
     def _score(
         self, total: np.ndarray, pf: np.ndarray, feasible: np.ndarray

@@ -48,10 +48,11 @@ Registered by name (mirroring the policy registry) so the simulator, the
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Dict, Optional, Tuple, Type
 
 import numpy as np
+
+from ..obs import hostspans
 
 __all__ = [
     "RecoveryStrategy",
@@ -208,9 +209,10 @@ class ReplanRecovery(_DelayedRecovery):
         engine._cancel_provisional(run, tasks=unstarted)
         for k in unstarted:
             del run.placement.tasks[k]
-        t0 = time.perf_counter()
-        plan = orchestrate(run.app, cluster, t, engine.policy, pinned=pinned)
-        engine.replan_time += time.perf_counter() - t0
+        with hostspans.span("plan.replan") as replan:
+            plan = orchestrate(run.app, cluster, t, engine.policy,
+                               pinned=pinned)
+        engine.replan_time += replan.ns / 1e9
         engine.stats.replans += 1
         if engine.trace is not None:
             engine.trace.event(

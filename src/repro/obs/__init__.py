@@ -13,7 +13,12 @@
     tier / device, slow- and lost-instance reports;
   * :mod:`repro.obs.export` — Chrome/Perfetto ``trace_event`` JSON
     (device rows + instance flows) and summary exports, with the
-    instance ledger recomputable from the exported trace alone.
+    instance ledger recomputable from the exported trace alone;
+  * :mod:`repro.obs.hostspans` — the wall-clock side: spans and timed
+    counters inside the planner, the policy and the engine
+    (:data:`HOST_SPAN_SCHEMA`) on ``time.perf_counter_ns``, kept only
+    while a ``jax.profiler`` session is active or after
+    ``hostspans.enable()``.
 
 Enable via ``Orchestrator(cluster, policy, trace=Tracer())`` or
 ``SimConfig(trace=True)``; see ``src/repro/obs/README.md`` for the span
@@ -41,6 +46,8 @@ from .metrics import (
     Histogram,
     MetricsRegistry,
 )
+from . import hostspans
+from .hostspans import HOST_SPAN_SCHEMA
 from .tracing import FLEET_TID, SPAN_SCHEMA, Span, Tracer
 
 __all__ = [
@@ -48,6 +55,8 @@ __all__ = [
     "Tracer",
     "SPAN_SCHEMA",
     "FLEET_TID",
+    "hostspans",
+    "HOST_SPAN_SCHEMA",
     "Counter",
     "Gauge",
     "Histogram",

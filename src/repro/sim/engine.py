@@ -57,6 +57,7 @@ from ..core.dag import AppDAG
 from ..core.orchestrator import Placement, Replica, orchestrate
 from ..core.policy import Policy, make_policy
 from ..core.recovery import RecoveryStrategy, make_recovery
+from ..obs import hostspans
 from ..obs.metrics import EngineStats
 from ..obs.tracing import FLEET_TID, Tracer
 
@@ -498,9 +499,9 @@ class Engine:
         for k in list(run.placement.tasks):
             if k not in pinned:
                 del run.placement.tasks[k]
-        t0 = time.perf_counter()
-        plan = orchestrate(run.app, cluster, t, self.policy, pinned=pinned)
-        self.replan_time += time.perf_counter() - t0
+        with hostspans.span("plan.replan") as replan:
+            plan = orchestrate(run.app, cluster, t, self.policy, pinned=pinned)
+        self.replan_time += replan.ns / 1e9
         if self.trace is not None:
             self.trace.event(
                 run.rec.tid, "salvage", t,
@@ -563,8 +564,44 @@ class Engine:
 
     # -- main loop -------------------------------------------------------------
     def run(self, until: float) -> None:
+        """Process every event with ``t <= until``.  While
+        :mod:`repro.obs.hostspans` records, the call is an ``engine.step``
+        span, each event is charged from its pop to the next one (one clock
+        read per event) and every T_alloc write is counted and timed."""
+        with hostspans.span("engine.step") as step:
+            if not step.recording:
+                self._run(until, None)
+                return
+            ns, n = [0] * 5, [0] * 5
+            launches = int(self.load.sum())
+            with self.cluster.timing_writes() as writes:
+                self._run(until, (ns, n))
+            step.set(arrival=n[self.ARRIVAL], arrival_ns=ns[self.ARRIVAL],
+                     task_end=n[self.TASK_END],
+                     task_end_ns=ns[self.TASK_END],
+                     other=sum(n[2:]), other_ns=sum(ns[2:]),
+                     launches=int(self.load.sum()) - launches,
+                     talloc_writes=writes[0], talloc_ns=writes[1])
+        hostspans.tally("engine.arrival", n[self.ARRIVAL], ns[self.ARRIVAL])
+        hostspans.tally("engine.task_end", n[self.TASK_END],
+                        ns[self.TASK_END])
+        hostspans.tally("engine.other", sum(n[2:]), sum(ns[2:]))
+        hostspans.tally("talloc.write", writes[0], writes[1])
+
+    def _run(self, until: float, timed: Optional[tuple]) -> None:
+        """The event loop; ``timed`` is ``(ns, n)`` per event kind, or None."""
+        if timed is not None:
+            ns, n = timed
+            clock = time.perf_counter_ns
+            last, t_last = -1, clock()
         while self.events and self.events[0][0] <= until:
             t, _, kind, payload = heapq.heappop(self.events)
+            if timed is not None:
+                t_pop = clock()
+                if last >= 0:
+                    ns[last] += t_pop - t_last
+                last, t_last = kind, t_pop
+                n[kind] += 1
             self.now = t
             if kind == self.ARRIVAL:
                 app, plan = payload
@@ -624,6 +661,8 @@ class Engine:
                 if (epoch == run.epoch and not run.failed
                         and not run.done.get(tname, False)):
                     self.recovery.recover(self, run, tname)
+        if timed is not None and last >= 0:
+            ns[last] += clock() - t_last
         self.now = until
 
     def drain(self) -> None:

@@ -28,12 +28,12 @@ T_alloc-style invariant for the queue.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, replace as _dc_replace
 from typing import List, Optional, Sequence, Tuple, Union
 
 from ..core.orchestrator import orchestrate_batch
 from ..core.policy import IBDASHPolicy, Policy
+from ..obs import hostspans
 from ..sim.engine import SimResult
 from .admission import (
     AdmissionConfig,
@@ -167,9 +167,8 @@ class StreamingOrchestrator:
             degraded = pol is not self.orch.policy
             apps = [a.instantiate() for a in arrivals]
             times = [now] * len(apps)
-            t0 = time.perf_counter()
             plans = orchestrate_batch(apps, cluster, pol, times=times)
-            dt = time.perf_counter() - t0
+            dt = hostspans.last_ns("plan.wave") / 1e9
             self._plan_time += dt
             self._planned += len(apps)
             self.metrics.histogram("wave_plan_s").observe(dt)

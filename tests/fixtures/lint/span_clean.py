@@ -1,8 +1,9 @@
 """Golden fixture: span-parity must stay SILENT on all of this.
 
 Run with options ``{"src_paths": ("",), "test_paths": (),
-"schema": ("exec", "plan")}`` — every emitted kind is a string literal
-present in the schema, and non-emission calls are ignored.
+"schema": ("exec", "plan"), "host_schema": ("plan.wave",)}`` — every
+emitted kind and wall-clock name is a string literal present in its
+schema, and non-emission calls are ignored.
 """
 
 
@@ -13,6 +14,13 @@ def emit(tracer, tid, now):
     tracer.add_span(tid, "exec", now, now + 1.0, device=4)
 
 
-def not_an_emission(queue, logger):
+def time_it(hostspans):
+    with hostspans.span("plan.wave", apps=3) as wave:
+        wave.set(planned=3)
+    hostspans.tally("plan.wave", 1, 10)
+
+
+def not_an_emission(queue, logger, other):
     queue.event(7)                      # one positional arg: no kind to audit
     logger.add_span()                   # no args at all
+    other.span("anything")              # not the hostspans module

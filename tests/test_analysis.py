@@ -69,6 +69,7 @@ FIXTURE_OPTIONS = {
         "src_paths": ("",),
         "test_paths": (),
         "schema": ("exec", "plan"),
+        "host_schema": ("plan.wave",),
     },
 }
 
@@ -95,7 +96,7 @@ MIN_VIOLATIONS = {
     "registry-parity": 1,     # mystery_scheme unpinned
     "kernel-hygiene": 4,      # f32 const + callback, 3-vs-1 lowerings, donation
     "unit-consistency": 5,    # s+B, B-vs-s, exp(s), where(s,B), prob-vs-count
-    "span-parity": 4,         # 2 off-schema kinds, 2 computed kinds
+    "span-parity": 6,         # 2+1 off-schema names, 2+1 computed names
 }
 
 
@@ -189,20 +190,25 @@ def test_parse_error_is_a_finding(tmp_path):
     assert report.exit_code == 1
 
 
-def test_span_parity_requires_test_pin(tmp_path):
-    """A kind emitted in src but never named in a scanned test file is an
-    unpinned span — and naming it silences the finding."""
+@pytest.mark.parametrize("emit, option, name", [
+    ('tr.event(tid, "exec", t)', "schema", "exec"),
+    ('hostspans.tally("plan.wave", 1, t)', "host_schema", "plan.wave"),
+])
+def test_span_parity_requires_test_pin(tmp_path, emit, option, name):
+    """A kind (or wall-clock name) emitted in src but never named in a
+    scanned test file is an unpinned span — and naming it silences the
+    finding."""
     (tmp_path / "src").mkdir()
     (tmp_path / "tests").mkdir()
     (tmp_path / "src" / "emit.py").write_text(
-        'def go(tr, tid, t):\n    tr.event(tid, "exec", t)\n'
+        f'def go(tr, hostspans, tid, t):\n    {emit}\n'
     )
     (tmp_path / "tests" / "test_spans.py").write_text("x = 'unrelated'\n")
-    opts = {"schema": ("exec",)}
+    opts = {option: (name,)}
     report = run_rule("span-parity", tmp_path, options=opts, root=tmp_path)
     assert len(report.findings) == 1
     assert "no behavioural pin" in report.findings[0].message
-    (tmp_path / "tests" / "test_spans.py").write_text("kinds = ('exec',)\n")
+    (tmp_path / "tests" / "test_spans.py").write_text(f"kinds = ({name!r},)\n")
     report = run_rule("span-parity", tmp_path, options=opts, root=tmp_path)
     assert report.findings == []
 

@@ -18,7 +18,11 @@ Pins the PR's contracts:
     (property-tested over random churn schedules);
   * :class:`EngineStats` turns a misspelled counter into an immediate
     ``AttributeError`` (satellite-1 regression) and checks conservation
-    in exactly one place.
+    in exactly one place;
+  * the wall-clock recorder (:mod:`repro.obs.hostspans`) keeps nothing
+    when off, nests its spans under their parents with one wave id per
+    ``orchestrate_batch`` call, counts exactly and repeatably, and leaves
+    plans and T_alloc bit-identical whether it records or not.
 """
 import json
 import math
@@ -27,18 +31,20 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
-from repro.api import Orchestrator, make_policy, make_recovery
+from repro.api import Orchestrator, make_policy, make_recovery, orchestrate_batch
 from repro.core.cluster import ClusterState, Device
 from repro.core.dag import AppDAG, TaskSpec
 from repro.core.interference import InterferenceModel
 from repro.obs import (
     ENGINE_COUNTERS,
     EngineStats,
+    HOST_SPAN_SCHEMA,
     SPAN_SCHEMA,
     Tracer,
     attribution_report,
     format_report,
     instance_breakdown,
+    hostspans,
     json_summary,
     ledger_from_trace,
     to_chrome_trace,
@@ -105,6 +111,37 @@ SPAN_KINDS = (
 def test_span_schema_is_frozen():
     assert tuple(SPAN_SCHEMA) == SPAN_KINDS
     assert all(isinstance(doc, str) and doc for doc in SPAN_SCHEMA.values())
+
+
+# The frozen wall-clock vocabulary of repro.obs.hostspans, spans first and
+# timed counters after: the span-parity rule's test pin for every name
+# passed to hostspans.span()/tally() in src (extend HOST_SPAN_SCHEMA,
+# obs/README.md and this tuple together).
+HOST_SPAN_NAMES = (
+    "plan.wave",
+    "plan.snapshot",
+    "plan.screen",
+    "plan.context",
+    "plan.assemble",
+    "plan.replan",
+    "policy.decide",
+    "policy.select",
+    "policy.kernel",
+    "engine.step",
+    "engine.arrival",
+    "engine.task_end",
+    "engine.other",
+    "talloc.write",
+)
+
+
+def test_host_span_schema_is_frozen():
+    assert tuple(HOST_SPAN_SCHEMA) == HOST_SPAN_NAMES
+    assert all(isinstance(doc, str) and doc
+               for doc in HOST_SPAN_SCHEMA.values())
+    # dotted names: none can be one of the chip benchmark's own span names
+    assert all("." in n and not n.startswith("kernel:")
+               for n in HOST_SPAN_NAMES)
 
 
 # ------------------------------------------------------------ tracer unit --
@@ -600,3 +637,268 @@ def test_stream_run_traces_admission(profile):
     snap = res.stream.metrics
     assert snap["counters"]["engine_admitted"] == led["admitted"]
     assert snap["counters"]["engine_shed"] == led["shed"]
+
+
+# ------------------------------------------------ the wall-clock recorder --
+def _bursts(profile, *, record, seed=7, n_cycles=2, per_cycle=120):
+    """Fused bursts planned at each cycle's start and stepped to the next,
+    as the chip benchmark's loop does, at a tiny size; the recorder on or
+    off.  Wrappers of the test's own count what the recorder should."""
+    cfg = SimConfig(n_cycles=n_cycles, instances_per_cycle=per_cycle,
+                    seed=seed, n_devices=24)
+    cluster = make_cluster(profile, scenario="mix", n_devices=24, seed=seed,
+                           horizon=cfg.horizon + 120.0)
+    policy = make_policy("ibdash", seed=seed)
+    orch = Orchestrator(cluster, policy, seed=seed)
+    apps, times = _make_workload(cfg)
+    seen = {"G": [], "rows": [], "writes": 0}
+    decide = policy.decide_batch
+
+    def decide_batch(batch):
+        seen["G"].append(batch.n_distinct)
+        seen["rows"].append(batch.n_rows)
+        return decide(batch)
+
+    add = ClusterState.add_interval
+
+    def add_interval(self, *args, **kw):
+        seen["writes"] += 1
+        return add(self, *args, **kw)
+
+    policy.decide_batch = decide_batch
+    ClusterState.add_interval = add_interval
+    plans = []
+    hostspans.clear()
+    if record:
+        hostspans.enable()
+    try:
+        for c in range(n_cycles):
+            lo, hi = c * cfg.cycle_len, (c + 1) * cfg.cycle_len
+            idx = [i for i, t in enumerate(times) if lo <= t < hi]
+            wave = orchestrate_batch([apps[i] for i in idx], cluster, policy,
+                                     times=[times[i] for i in idx])
+            orch.engine.add_arrivals([apps[i] for i in idx],
+                                     [times[i] for i in idx], plans=wave)
+            plans += wave
+            orch.step(hi)
+    finally:
+        hostspans.disable()
+        ClusterState.add_interval = add
+    out = {
+        "submitted": len(apps),
+        "seen": seen,
+        "plans": [
+            (p.feasible, p.est_latency, [
+                (k, tp.ttype, tp.est_start,
+                 [(r.did, r.est_exec, r.est_upload, r.est_transfer,
+                   r.pred_fail) for r in tp.replicas])
+                for k, tp in p.tasks.items()])
+            for p in plans
+        ],
+        "alloc": cluster.alloc.copy(),
+        "records": hostspans.records(),
+        "timed": {n: hostspans.timed(n) for n in HOST_SPAN_NAMES},
+        "wave_ns": hostspans.last_ns("plan.wave"),
+    }
+    hostspans.clear()
+    return out
+
+
+@pytest.fixture(scope="module")
+def recorded(profile):
+    return _bursts(profile, record=True)
+
+
+def _shape(run):
+    """Everything a recorded run says except its times and ids."""
+    by_id = {r.id: r for r in run["records"]}
+    return (
+        [(r.name, by_id[r.parent].name if r.parent else None,
+          {k: v for k, v in r.attrs.items() if not k.endswith("_ns")})
+         for r in run["records"]],
+        {n: c for n, (c, _) in run["timed"].items()},
+    )
+
+
+def test_host_recorder_off_keeps_nothing(profile, monkeypatch):
+    """No profiler session and no enable(): no record, no counter and no
+    profiler annotation — yet every span still timed itself."""
+    import jax
+
+    made = []
+
+    class Annotation:
+        is_enabled = staticmethod(lambda: False)
+
+        def __init__(self, name, **kw):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    assert not hostspans.recording()
+    run = _bursts(profile, record=False, n_cycles=1, per_cycle=40)
+    assert run["records"] == [] and made == []
+    assert all(v == (0, 0) for v in run["timed"].values())
+    assert run["wave_ns"] > 0
+
+
+def test_host_spans_nest_under_their_parents(recorded):
+    """Parent links follow the call tree, every nested span lies inside its
+    parent, and all spans of one orchestrate_batch call share its wave id."""
+    recs = recorded["records"]
+    by_id = {r.id: r for r in recs}
+    parent_of = {
+        "plan.snapshot": "plan.wave", "plan.screen": "plan.wave",
+        "plan.context": "plan.wave", "plan.assemble": "plan.wave",
+        "policy.decide": "plan.wave", "policy.select": "policy.decide",
+        "policy.kernel": "policy.decide",
+    }
+    names = {r.name for r in recs}
+    assert names == set(parent_of) | {"plan.wave", "engine.step"}
+    assert sum(r.name == "plan.wave" for r in recs) == 2
+    assert sum(r.name == "engine.step" for r in recs) == 2
+    for r in recs:
+        assert r.t0 <= r.t1
+        if r.name in ("plan.wave", "engine.step"):
+            assert r.parent is None
+            assert r.wave == (r.id if r.name == "plan.wave" else None)
+            continue
+        up = by_id[r.parent]
+        assert up.name == parent_of[r.name]
+        assert up.t0 <= r.t0 and r.t1 <= up.t1
+        assert r.wave == up.wave and by_id[r.wave].name == "plan.wave"
+
+
+@pytest.mark.parametrize("count", [
+    "arrivals", "talloc_writes", "distinct_rows", "rows_kept", "launches"])
+def test_host_counts_are_exact(recorded, count):
+    recs, seen = recorded["records"], recorded["seen"]
+    steps = [r for r in recs if r.name == "engine.step"]
+    if count == "arrivals":
+        assert recorded["timed"]["engine.arrival"][0] == recorded["submitted"]
+        assert sum(r.attrs["arrival"] for r in steps) == recorded["submitted"]
+    elif count == "talloc_writes":
+        # planning is pure, so every write of the run was the engine's
+        assert recorded["timed"]["talloc.write"][0] == seen["writes"] > 0
+        assert sum(r.attrs["talloc_writes"] for r in steps) == seen["writes"]
+    elif count == "distinct_rows":
+        got = [r.attrs["G"] for r in recs if r.name == "plan.context"]
+        assert got == seen["G"]
+        kernel = [r.attrs["G"] for r in recs if r.name == "policy.kernel"]
+        assert kernel and kernel == [g for g in seen["G"] if g >= 8]
+    elif count == "rows_kept":
+        got = [r.attrs["rows_kept"] for r in recs
+               if r.name == "plan.screen" and r.attrs["rows_kept"]]
+        assert got == seen["rows"]
+        assert [r.attrs["B"] for r in recs if r.name == "policy.decide"] \
+            == seen["rows"]
+    else:
+        # every replica launch is a T_alloc write at its start, and the
+        # engine's three timed counters split its events between them
+        launches = sum(r.attrs["launches"] for r in steps)
+        assert 0 < launches <= seen["writes"]
+        events = sum(r.attrs["arrival"] + r.attrs["task_end"]
+                     + r.attrs["other"] for r in steps)
+        assert recorded["timed"]["engine.task_end"][0] == \
+            sum(r.attrs["task_end"] for r in steps) == launches
+        assert events == recorded["submitted"] + launches
+
+
+def test_host_counts_repeat_on_the_same_seed(profile, recorded):
+    again = _bursts(profile, record=True)
+    assert _shape(again) == _shape(recorded)
+
+
+def test_recording_leaves_plans_and_talloc_bit_identical(profile, recorded):
+    off = _bursts(profile, record=False)
+    assert off["records"] == []
+    assert off["plans"] == recorded["plans"]
+    assert off["alloc"].tobytes() == recorded["alloc"].tobytes()
+    assert off["seen"] == recorded["seen"]
+
+
+def test_engine_timed_counters_partition_the_step(recorded):
+    """Each event is charged from its pop to the next pop, so the three
+    event counters add up to nearly all of engine.step."""
+    for r in (r for r in recorded["records"] if r.name == "engine.step"):
+        charged = (r.attrs["arrival_ns"] + r.attrs["task_end_ns"]
+                   + r.attrs["other_ns"])
+        assert r.attrs["talloc_ns"] < charged <= r.ns
+
+
+def test_host_recorder_follows_the_profiler(profile, tmp_path):
+    """A jax.profiler session turns recording on with no enable(), and
+    the coarse spans then come with profiler annotations."""
+    import jax
+
+    cluster = make_cluster(profile, scenario="mix", n_devices=12, seed=4,
+                           horizon=200.0)
+    apps, times = _make_workload(SimConfig(n_cycles=1, instances_per_cycle=30,
+                                           seed=4, n_devices=12))
+    hostspans.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        assert hostspans.recording()
+        orchestrate_batch(apps, cluster, "ibdash", times=times)
+    assert not hostspans.recording()
+    names = {r.name for r in hostspans.records()}
+    hostspans.clear()
+    assert {"plan.wave", "plan.snapshot", "plan.screen", "plan.context",
+            "plan.assemble", "policy.decide"} <= names
+
+
+def test_replan_time_reads_the_replan_spans(profile, traced):
+    """Engine.replan_time keeps its meaning: the wall time of the recovery
+    replans, read from their plan.replan spans, recorded or not."""
+    assert traced[0].engine.replan_time > 0.0
+    hostspans.clear()
+    hostspans.enable()
+    try:
+        orch, _, _ = _traced_orchestrator(profile)
+    finally:
+        hostspans.disable()
+    replans = hostspans.records("plan.replan")
+    other = hostspans.timed("engine.other")
+    hostspans.clear()
+    assert len(replans) == orch.stats["replans"] + orch.stats["salvages"]
+    assert orch.engine.replan_time == pytest.approx(
+        sum(r.ns for r in replans) / 1e9, rel=1e-9)
+    assert other[0] >= orch.stats["device_down"] + orch.stats["device_up"]
+
+
+def test_wave_plan_metrics_read_the_wave_spans(profile, monkeypatch):
+    """The service's wave_plan_s histogram and placements_per_sec gauge
+    keep their names and meaning: the plan.wave span of each wave the
+    service plans (the admission estimator plans probes of its own)."""
+    from repro.stream import service
+
+    plan = service.orchestrate_batch
+    dispatched = []
+
+    def orchestrate_batch(apps, *args, **kw):
+        plans = plan(apps, *args, **kw)
+        dispatched.append(hostspans.records("plan.wave")[-1])
+        return plans
+
+    monkeypatch.setattr(service, "orchestrate_batch", orchestrate_batch)
+    cfg = SimConfig(scenario="stream", n_cycles=1, cycle_len=6.0,
+                    seed=2, n_devices=8, stream_rate=80.0)
+    hostspans.clear()
+    hostspans.enable()
+    try:
+        res = run_one("ibdash", cfg, profile)
+    finally:
+        hostspans.disable()
+        hostspans.clear()
+    snap = res.stream.metrics
+    h = snap["histograms"]["wave_plan_s"]
+    total = sum(w.ns for w in dispatched) / 1e9
+    assert h["count"] == len(dispatched) > 0
+    assert h["mean"] * h["count"] == pytest.approx(total, rel=1e-9)
+    planned = sum(w.attrs["apps"] for w in dispatched)
+    assert snap["gauges"]["placements_per_sec"] == pytest.approx(
+        planned / total, rel=1e-9)
