@@ -19,6 +19,19 @@ while the program planned and stepped.  Two kinds of site, both named in
     ``(count, ns)`` in locals and hands the totals over once, so nothing is
     kept per call and no profiler event is made.
 
+A third kind, also named there, comes from the runtime, not from a call
+site: a ``gc.callbacks`` hook installed on import times every pass of
+CPython's cyclic garbage collector and, while spans are kept, keeps one
+``gc.collect`` record per pass (attributes ``generation``, ``collected``,
+``uncollectable``) under the innermost open span, and tallies the timed
+counter ``gc.pause``.  A pass is charged to whichever span is open when it
+fires; these records say how much of that span was the collector.  The
+hook runs in the middle of arbitrary allocations, so it never calls into
+JAX or the profiler and makes no annotation; it cannot ask whether a
+profiler session is on, so under a session alone it keeps the passes that
+fire inside a recording span, and after :func:`enable` every pass (one
+outside every span has no parent).  Otherwise it costs one flag check.
+
 When it records: while a ``jax.profiler`` session is active
 (``TraceAnnotation.is_enabled()``), or between :func:`enable` and
 :func:`disable`.  Otherwise a coarse span costs one clock pair and a flag
@@ -27,7 +40,7 @@ call of the loop it counts.  Every span measures its own duration either
 way (``sp.ns``; :func:`last_ns` for the latest span of a name), which is
 what the service's ``wave_plan_s`` and the engine's ``replan_time`` read.
 
-Records go to a bounded buffer (the newest :data:`BUFFER` spans) that
+Records go to a bounded buffer (the newest :data:`BUFFER`) that
 :func:`clear` empties; timed counters go to a
 :class:`~repro.obs.metrics.MetricsRegistry` (``<name>`` counts calls,
 ``<name>.ns`` their nanoseconds).  The recorder is process-wide and
@@ -37,6 +50,7 @@ audits them); a recorded name outside it raises.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import sys
 import time
@@ -60,11 +74,12 @@ __all__ = [
     "last_ns",
 ]
 
-# name -> one-line contract.  Spans first, then timed counters.  The
-# span-parity lint rule requires every name passed to span()/tally() in
-# src/repro to be a literal found here AND named in the test suite.  No
-# name may equal one of the benchmark's own span names (window,
-# orchestrate_batch, decide_batch, step) or start with "kernel:".
+# name -> one-line contract.  Spans and records first, then timed
+# counters.  The span-parity lint rule requires every name passed to
+# span()/tally() in src/repro to be a literal found here AND named in the
+# test suite; _record() checks its name here at run time.  No name may
+# equal one of the benchmark's own span names (window, orchestrate_batch,
+# decide_batch, step) or start with "kernel:".
 HOST_SPAN_SCHEMA: Dict[str, str] = {
     "plan.wave": "span: the whole of one orchestrate_batch call; its id is "
                  "the wave id of every span inside (attrs: apps, stages, "
@@ -89,6 +104,9 @@ HOST_SPAN_SCHEMA: Dict[str, str] = {
                      "and read-back (attrs: G, rows)",
     "engine.step": "span: one Engine.run(until) (attrs: arrival, task_end, "
                    "other, launches, talloc_writes and the matching *_ns)",
+    "gc.collect": "record: one pass of the cyclic garbage collector, from "
+                  "the gc.callbacks hook, under the innermost open span "
+                  "(attrs: generation, collected, uncollectable)",
     "engine.arrival": "timed counter: ARRIVAL events, heappop to the next "
                       "pop (apply into T_alloc, record, first launches)",
     "engine.task_end": "timed counter: TASK_END events, heappop to the "
@@ -97,9 +115,12 @@ HOST_SPAN_SCHEMA: Dict[str, str] = {
                     "events, heappop to the next pop",
     "talloc.write": "timed counter: ClusterState.add_interval calls made "
                     "inside Engine.run, over all its callers",
+    "gc.pause": "timed counter: the passes of the garbage collector kept "
+                "as gc.collect records",
 }
 
-BUFFER = 1 << 16            # recorded spans kept (about 35 per wave)
+BUFFER = 1 << 16            # records kept (about 35 spans and 55-90
+                            # collector passes per wave)
 
 _clock = time.perf_counter_ns
 _ids = itertools.count(1)
@@ -108,6 +129,7 @@ _stack: List["HostSpan"] = []
 _records: Deque["HostSpan"] = deque(maxlen=BUFFER)
 _counters = MetricsRegistry()
 _last: Dict[str, int] = {}
+_gc_t0 = 0                  # start of the collector pass being kept
 
 
 def _profiling() -> bool:
@@ -124,14 +146,14 @@ def recording() -> bool:
 
 def enable() -> None:
     """Record with no profiler session (tests, operators)."""
-    global _forced
-    _forced = True
+    global _forced, _gc_t0
+    _forced, _gc_t0 = True, 0
 
 
 def disable() -> None:
     """Stop recording outside a profiler session."""
-    global _forced
-    _forced = False
+    global _forced, _gc_t0
+    _forced, _gc_t0 = False, 0
 
 
 def clear() -> None:
@@ -211,6 +233,39 @@ def tally(name: str, n: int, ns: int) -> None:
                          "HOST_SPAN_SCHEMA (and obs/README.md) first")
     _counters.counter(name).inc(n)
     _counters.counter(name + ".ns").inc(ns)
+
+
+def _record(name: str, t0: int, t1: int, **attrs: Any) -> None:
+    """Keep a closed record of work timed elsewhere, under the innermost
+    open span."""
+    if name not in HOST_SPAN_SCHEMA:
+        raise ValueError(f"unknown host record {name!r}; add it to "
+                         "HOST_SPAN_SCHEMA (and obs/README.md) first")
+    rec = HostSpan(name, attrs)
+    rec.t0, rec.t1, rec.id, rec.recording = t0, t1, next(_ids), True
+    if _stack:
+        top = _stack[-1]
+        rec.parent, rec.wave = top.id, top.wave
+    _records.append(rec)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """The ``gc.callbacks`` hook: one ``gc.collect`` record and a
+    ``gc.pause`` tally per pass while spans are kept (module docstring)."""
+    global _gc_t0
+    if not (_forced or _stack):
+        return
+    if phase == "start":
+        _gc_t0 = _clock()
+    elif _gc_t0:
+        t0, t1, _gc_t0 = _gc_t0, _clock(), 0
+        _record("gc.collect", t0, t1, generation=info["generation"],
+                collected=info["collected"],
+                uncollectable=info["uncollectable"])
+        tally("gc.pause", 1, t1 - t0)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def records(name: Optional[str] = None) -> List[HostSpan]:

@@ -22,8 +22,12 @@ Pins the PR's contracts:
   * the wall-clock recorder (:mod:`repro.obs.hostspans`) keeps nothing
     when off, nests its spans under their parents with one wave id per
     ``orchestrate_batch`` call, counts exactly and repeatably, and leaves
-    plans and T_alloc bit-identical whether it records or not.
+    plans and T_alloc bit-identical whether it records or not;
+  * its garbage-collector hook keeps one ``gc.collect`` record per pass
+    under the innermost open span while spans are kept, nothing otherwise,
+    never calls JAX, and its ``gc.pause`` counter sums the records.
 """
+import gc
 import json
 import math
 
@@ -113,10 +117,10 @@ def test_span_schema_is_frozen():
     assert all(isinstance(doc, str) and doc for doc in SPAN_SCHEMA.values())
 
 
-# The frozen wall-clock vocabulary of repro.obs.hostspans, spans first and
-# timed counters after: the span-parity rule's test pin for every name
-# passed to hostspans.span()/tally() in src (extend HOST_SPAN_SCHEMA,
-# obs/README.md and this tuple together).
+# The frozen wall-clock vocabulary of repro.obs.hostspans, spans and
+# records first and timed counters after: the span-parity rule's test pin
+# for every name passed to hostspans.span()/tally() in src (extend
+# HOST_SPAN_SCHEMA, obs/README.md and this tuple together).
 HOST_SPAN_NAMES = (
     "plan.wave",
     "plan.snapshot",
@@ -128,10 +132,12 @@ HOST_SPAN_NAMES = (
     "policy.select",
     "policy.kernel",
     "engine.step",
+    "gc.collect",
     "engine.arrival",
     "engine.task_end",
     "engine.other",
     "talloc.write",
+    "gc.pause",
 )
 
 
@@ -696,7 +702,10 @@ def _bursts(profile, *, record, seed=7, n_cycles=2, per_cycle=120):
             for p in plans
         ],
         "alloc": cluster.alloc.copy(),
-        "records": hostspans.records(),
+        # the collector's passes fire wherever allocation calls for them,
+        # so they are kept apart from the program's repeatable spans
+        "records": [r for r in hostspans.records() if r.name != "gc.collect"],
+        "gc": hostspans.records("gc.collect"),
         "timed": {n: hostspans.timed(n) for n in HOST_SPAN_NAMES},
         "wave_ns": hostspans.last_ns("plan.wave"),
     }
@@ -710,13 +719,14 @@ def recorded(profile):
 
 
 def _shape(run):
-    """Everything a recorded run says except its times and ids."""
+    """Everything a recorded run says except its times and ids, and the
+    collector's passes (which follow the process's allocations)."""
     by_id = {r.id: r for r in run["records"]}
     return (
         [(r.name, by_id[r.parent].name if r.parent else None,
           {k: v for k, v in r.attrs.items() if not k.endswith("_ns")})
          for r in run["records"]],
-        {n: c for n, (c, _) in run["timed"].items()},
+        {n: c for n, (c, _) in run["timed"].items() if n != "gc.pause"},
     )
 
 
@@ -742,7 +752,7 @@ def test_host_recorder_off_keeps_nothing(profile, monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
     assert not hostspans.recording()
     run = _bursts(profile, record=False, n_cycles=1, per_cycle=40)
-    assert run["records"] == [] and made == []
+    assert run["records"] == [] and run["gc"] == [] and made == []
     assert all(v == (0, 0) for v in run["timed"].values())
     assert run["wave_ns"] > 0
 
@@ -902,3 +912,121 @@ def test_wave_plan_metrics_read_the_wave_spans(profile, monkeypatch):
     planned = sum(w.attrs["apps"] for w in dispatched)
     assert snap["gauges"]["placements_per_sec"] == pytest.approx(
         planned / total, rel=1e-9)
+
+
+# ------------------------------------------- the garbage-collector hook --
+GC_INFO = {"generation": 0, "collected": 0, "uncollectable": 0}
+
+
+@pytest.fixture
+def collector_quiet():
+    """No automatic collection while the test runs (explicit passes still
+    run the callbacks), and the recorder left off and empty after it."""
+    was = gc.isenabled()
+    gc.disable()
+    hostspans.clear()
+    try:
+        yield
+    finally:
+        hostspans.disable()
+        hostspans.clear()
+        if was:
+            gc.enable()
+
+
+def test_forced_collection_in_a_recorded_span_is_one_record(collector_quiet):
+    hostspans.enable()
+    with hostspans.span("plan.wave"):
+        with hostspans.span("plan.assemble") as sp:
+            gc.collect()
+    hostspans.disable()
+    rec, = hostspans.records("gc.collect")
+    assert rec.attrs["generation"] == 2
+    assert set(rec.attrs) == {"generation", "collected", "uncollectable"}
+    assert rec.parent == sp.id and rec.wave == sp.wave == sp.parent
+    assert sp.t0 <= rec.t0 < rec.t1 <= sp.t1
+    assert hostspans.timed("gc.pause") == (1, rec.ns)
+    # a pass outside every span under enable() is kept without a parent
+    hostspans.enable()
+    gc.collect(0)
+    rec = hostspans.records("gc.collect")[-1]
+    assert rec.parent is None and rec.wave is None
+    assert rec.attrs["generation"] == 0
+
+
+def test_collector_hook_keeps_nothing_while_off(collector_quiet,
+                                                monkeypatch):
+    """Off, a pass is only a flag check: no record, no counter.  In no
+    state does the hook ask JAX's profiler anything."""
+    import jax
+
+    asked = []
+
+    class Annotation:
+        @staticmethod
+        def is_enabled():
+            asked.append("is_enabled")
+            return False
+
+        def __init__(self, name, **kw):
+            asked.append(name)
+
+    assert not hostspans.recording()
+    with hostspans.span("plan.wave"):
+        gc.collect()
+    assert hostspans.records("gc.collect") == []
+    assert hostspans.timed("gc.pause") == (0, 0)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    hostspans._on_gc("start", GC_INFO)
+    hostspans._on_gc("stop", GC_INFO)
+    hostspans.enable()
+    hostspans._on_gc("start", GC_INFO)
+    hostspans._on_gc("stop", GC_INFO)
+    assert asked == []
+    assert len(hostspans.records("gc.collect")) == 1
+
+
+def test_clear_and_disable_leave_the_collector_hook_harmless(
+        collector_quiet):
+    assert gc.callbacks.count(hostspans._on_gc) == 1
+    hostspans.enable()
+    gc.collect()
+    hostspans.clear()
+    assert hostspans.records() == [] and hostspans.timed("gc.pause") == (0, 0)
+    gc.collect()
+    assert len(hostspans.records("gc.collect")) == 1
+    assert hostspans.timed("gc.pause")[0] == 1
+    # a pass that began while recording and ended after disable() is
+    # dropped, and its start is not carried over to a later pass's end
+    hostspans._on_gc("start", GC_INFO)
+    hostspans.disable()
+    hostspans._on_gc("stop", GC_INFO)
+    hostspans.enable()
+    hostspans._on_gc("stop", GC_INFO)
+    hostspans.disable()
+    gc.collect()
+    assert len(hostspans.records("gc.collect")) == 1
+    assert hostspans.timed("gc.pause")[0] == 1
+
+
+def test_record_outside_the_schema_raises(collector_quiet):
+    hostspans.enable()
+    with pytest.raises(ValueError, match="gc.rogue"):
+        hostspans._record("gc.rogue", 1, 2)
+    hostspans._record("gc.collect", 1, 2, generation=0)
+    assert [r.name for r in hostspans.records()] == ["gc.collect"]
+
+
+def test_gc_pause_is_the_sum_of_the_records(recorded):
+    """Over the recorded bursts the collector ran on its own; its counter
+    is the records' count and time, and each record lies in its parent."""
+    recs = recorded["gc"]
+    assert recs and all(r.name == "gc.collect" for r in recs)
+    assert recorded["timed"]["gc.pause"] == (len(recs),
+                                             sum(r.ns for r in recs))
+    by_id = {r.id: r for r in recorded["records"]}
+    for r in recs:
+        assert 0 < r.ns and r.attrs["generation"] in (0, 1, 2)
+        if r.parent is not None:
+            up = by_id[r.parent]
+            assert up.t0 <= r.t0 and r.t1 <= up.t1 and r.wave == up.wave
