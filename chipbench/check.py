@@ -1,12 +1,16 @@
 """How ``correct`` is decided: the window's outputs against the reference.
 
 After the window (and after the device's peak memory was read) the
-recorded schedule is replayed through :class:`reference.ReferenceSim`.  A
-sample of the planned instances, drawn from the seed, has every task's
-pricing and selection recomputed at the state its wave was planned
-against; the whole replay's T_alloc and outcome counts are compared with
-the program's at the end.  Three numbers, each with its limit from the
-configuration file:
+recorded schedule is replayed through the reference of the
+configuration's deployment module (``deployments/<name>.py``; the default
+``paper`` replays through :class:`reference.ReferenceSim`).  The replay
+takes ``submit`` and ``step`` ops here; an op of any other kind goes to
+the reference's ``replay_<kind>``, and a kind it has no handler for fails
+the run.  A sample of the planned instances, drawn from the seed, has
+every task's pricing and selection recomputed at the state its wave was
+planned against; the whole replay's T_alloc and outcome counts are
+compared with the program's at the end.  Three numbers, each with its
+limit from the configuration file:
 
 * ``plan_gap``  — the widest relative gap of a sampled plan from the
   reference: a chosen device's Eq. 2 latency above the best feasible one,
@@ -16,15 +20,19 @@ configuration file:
 * ``unplanned`` — instances submitted without a plan, or planned
   infeasible while the reference finds devices for every task (exact);
 * ``state_gap`` — the largest difference of the program's T_alloc and its
-  completed / lost counts from the replay's (integer-valued, exact).
+  completed / lost counts from the replay's (integer-valued, exact);
+
+and whatever further numbers the deployment's reference returns from
+``checks()``, each with its limit from the same file.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from reference import App, PlanView, Readings, ReferenceSim
+from harness import RunError
+from reference import App, PlanView, Readings
 from traffic import Builder, rng
 
 
@@ -45,16 +53,14 @@ def _sample(run) -> set:
     return picked
 
 
-def readings(run, control_dtype=None) -> Tuple[Readings, Optional[Readings]]:
-    """Replay the window; return the program's readings and, with
-    ``control_dtype``, the control's (the reference in that precision put
-    in the program's place on the same sampled tasks)."""
+def readings(run, control_dtype=None
+             ) -> Tuple[Readings, Optional[Readings], Dict[str, float]]:
+    """Replay the window; return the program's readings, with
+    ``control_dtype`` the control's (the reference in that precision put
+    in the program's place on the same sampled tasks), and the further
+    numbers of the deployment's reference."""
     s = run.setup
-    p = s.config["policy"]
-    sim = ReferenceSim(s.fleet, seed=s.seed,
-                       noise_sigma=float(s.config["noise_sigma"]),
-                       alpha=float(p["alpha"]), beta=float(p["beta"]),
-                       gamma=int(p["gamma"]))
+    sim = s.deployment.reference(s)
     out = Readings()
     ctl = Readings() if control_dtype is not None else None
     picked = _sample(run)
@@ -62,6 +68,13 @@ def readings(run, control_dtype=None) -> Tuple[Readings, Optional[Readings]]:
     for i, op in enumerate(run.schedule):
         if op[0] == "step":
             sim.step(op[1])
+            continue
+        if op[0] != "submit":
+            replay = getattr(sim, f"replay_{op[0]}", None)
+            if replay is None:
+                raise RunError(f"schedule op {op[0]!r}: the deployment's "
+                               f"reference has no replay_{op[0]}")
+            replay(*op[1:])
             continue
         _, apps, times, plans = op
         wave_now = min(times)
@@ -79,7 +92,7 @@ def readings(run, control_dtype=None) -> Tuple[Readings, Optional[Readings]]:
                           if v is not None]) if any(views) else ([], [], []))
     stats = s.orch.stats
     out.state_gap = sim.state_gap(s.cluster.alloc, stats.completed, stats.lost)
-    return out, ctl
+    return out, ctl, sim.checks()
 
 
 def compare(run, control_dtype=None) -> dict:
@@ -87,10 +100,8 @@ def compare(run, control_dtype=None) -> dict:
     control's readings: the reference in that precision put in the
     program's place on the same sampled tasks.  The control plans only
     those tasks, so it has a ``plan_gap`` and no other number."""
-    out, ctl = readings(run, control_dtype)
+    out, ctl, extra = readings(run, control_dtype)
     if out.checked_tasks == 0 and out.unplanned == 0:
-        from harness import RunError
-
         raise RunError("no planned task was compared with the reference")
     lim = run.setup.config["limits"]
     if ctl is not None:
@@ -99,11 +110,17 @@ def compare(run, control_dtype=None) -> dict:
         return {"plan_gap": {"value": ctl.plan_gap, "limit": lim["plan_gap"]}}
     run.notes["checked"] = (f"{out.checked_apps} instances, "
                             f"{out.checked_tasks} tasks; widest at {out.worst}")
-    return {
+    checks = {
         "plan_gap": {"value": out.plan_gap, "limit": lim["plan_gap"]},
         "unplanned": {"value": out.unplanned, "limit": lim["unplanned"]},
         "state_gap": {"value": out.state_gap, "limit": lim["state_gap"]},
     }
+    for name, value in extra.items():
+        if name in checks or name not in lim:
+            raise RunError(f"the reference's number {name!r} has no limit of "
+                           "its own in the configuration's limits")
+        checks[name] = {"value": value, "limit": lim[name]}
+    return checks
 
 
 def verdict(checks: dict) -> bool:

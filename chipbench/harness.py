@@ -5,8 +5,12 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric sits in a file of its own under ``chipbench/`` and is found by the
 name ``BENCHMARK.json`` gives it:
 
-* ``configs/<config>.json``  — the deployment (fleet, policy, T_alloc
-  window, the limits of the comparison);
+* ``configs/<config>.json``  — the deployment's sizes (fleet, policy,
+  T_alloc window, the limits of the comparison); ``deployment_module``
+  names ``deployments/<name>.py``, by default ``paper``;
+* ``deployments/<name>.py``  — what set-up builds from the configuration,
+  its warm-up, and the reference ``check.py`` replays against
+  (``deployments/README.md``);
 * ``traffic/<traffic>.json`` — the mix; ``driver`` names
   ``drivers/<driver>.py``, the rest are that driver's parameters;
 * ``metrics/<metric>.py``    — ``read(run)`` returns the metric or None.
@@ -23,8 +27,6 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
-import numpy as np
-
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 
@@ -39,8 +41,10 @@ def load_json(path: Path) -> dict:
 
 
 def load_module(path: Path):
-    """Import a file by path (metric files have dots in their names)."""
-    name = "chipbench_" + "".join(c if c.isalnum() else "_" for c in path.stem)
+    """Import a file by path (metric files have dots in their names), once
+    per file: a driver and a deployment may share a name."""
+    name = "chipbench_" + "".join(c if c.isalnum() else "_"
+                                  for c in str(Path(path).resolve()))
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -56,16 +60,27 @@ def cache_dir(root: Path) -> str:
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(root / ".jax_cache")
 
 
+def deployment_for(config: dict, root: Path = ROOT):
+    """The deployment module a configuration names (``deployment_module``),
+    ``paper`` where it names none."""
+    name = config.get("deployment_module", "paper")
+    path = root / HERE.name / "deployments" / f"{name}.py"
+    if not path.is_file():
+        raise RunError(f"no deployment module {name!r} at {path}")
+    return load_module(path)
+
+
 @dataclass
 class Setup:
-    """What set-up builds from the seed: the fleet, the policy, the
-    orchestrator with its instrumentation, and the fleet as data for the
-    reference."""
+    """What set-up builds from the seed through the configuration's
+    deployment module: the fleet, the policy, the orchestrator with its
+    instrumentation, and the fleet as data for the reference."""
 
     config: dict
     traffic: dict
     seed: int
     trace: bool
+    deployment: object = None
     cluster: object = None
     orch: object = None
     policy: object = None
@@ -73,57 +88,20 @@ class Setup:
     fleet: object = None
 
     def build(self) -> "Setup":
-        from repro.api import Orchestrator, make_policy
-        from repro.sim import make_cluster, make_profile
-
         from instrument import Instrument
-        from reference import Fleet
 
-        c = self.config
-        profile = make_profile(seed=int(c["profile_seed"]))
-        self.cluster = make_cluster(
-            profile, scenario=c["scenario"], n_devices=int(c["n_devices"]),
-            seed=self.seed, horizon=float(c["horizon_s"]), dt=float(c["dt"]),
-        )
-        p = c["policy"]
-        self.policy = make_policy(p["name"], alpha=p["alpha"], beta=p["beta"],
-                                  gamma=p["gamma"], seed=self.seed)
-        self.orch = Orchestrator(self.cluster, self.policy, seed=self.seed,
-                                 noise_sigma=float(c["noise_sigma"]))
+        self.deployment.build(self)
         self.inst = Instrument(trace=self.trace).install(self.orch, self.policy)
-        cl = self.cluster
-        self.fleet = Fleet.read(cl.devices, cl.model.base, cl.model.slope,
-                                cl.backhaul, cl.model_source, cl.dt, cl.horizon)
         return self
 
     def warm_fleet(self, t: float) -> None:
-        """Plan one instance of each app at sim time ``t`` and discard the
-        plans (planning is pure; nothing is submitted).  The program
-        allocates T_alloc lazily, so the first plan of a process touches
-        every device's rows for the first time; a long-running service has
-        paid that long before, so set-up pays it here."""
-        from repro import api
-
-        from traffic import APPS, Builder, Due
-
-        b = Builder()
-        apps = [b.app(Due(t, k, -1 - i)) for i, k in enumerate(APPS)]
-        api.orchestrate_batch(apps, self.cluster, self.policy,
-                              times=[t] * len(apps))
+        """The deployment's warm-up of fleet state at sim time ``t``."""
+        self.deployment.warm_fleet(self, t)
 
     def warm_kernels(self, rows: List[int]) -> None:
-        """Compile (or load from the persistent cache) the placement kernel
-        at each padded row count the traffic produces, through the same
-        host entry ``decide_batch`` calls."""
-        from repro.core.batched import ibdash_decide_batch
-
-        p = self.config["policy"]
-        r = np.random.default_rng(0)
-        for g in rows:
-            total = r.uniform(1.0, 2.0, (g, 16))
-            pf = r.uniform(0.0, 0.3, (g, 16))
-            ibdash_decide_batch(total, pf, np.ones((g, 16), bool),
-                                p["alpha"], p["beta"], p["gamma"])
+        """The deployment's warm-up of its kernels at each padded row count
+        the traffic produces."""
+        self.deployment.warm_kernels(self, rows)
 
 
 @dataclass
@@ -197,12 +175,14 @@ def measure(bench: dict, wl: dict, seed: int, seconds: float, trace: bool,
 
     cfg_entry = next(c for c in bench["configs"] if c["name"] == wl["config"])
     config = {**load_json(root / cfg_entry["file"]), **(config_overrides or {})}
-    traffic = {**load_json(HERE / "traffic" / f"{wl['traffic']}.json"),
+    bench_dir = root / HERE.name
+    traffic = {**load_json(bench_dir / "traffic" / f"{wl['traffic']}.json"),
                **(traffic_overrides or {})}
-    driver = load_module(HERE / "drivers" / f"{traffic['driver']}.py")
+    driver = load_module(bench_dir / "drivers" / f"{traffic['driver']}.py")
+    deployment = deployment_for(config, root)
 
     t_enter = time.perf_counter()
-    setup = Setup(config, traffic, seed, trace).build()
+    setup = Setup(config, traffic, seed, trace, deployment).build()
     t_built = time.perf_counter()
     run = RunRecord(setup=setup, seconds=float(seconds))
     try:
