@@ -48,6 +48,7 @@ Registered by name (mirroring the policy registry) so the simulator, the
 """
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Callable, Dict, Optional, Tuple, Type
 
 import numpy as np
@@ -177,7 +178,8 @@ class FailoverRecovery(_DelayedRecovery):
         if rep is None:
             engine._finish_app(run, failed=True)
             return
-        run.placement.tasks[tname].replicas = [rep]
+        tasks = engine.own_placement(run).tasks
+        tasks[tname] = replace(tasks[tname], replicas=[rep])
         engine._launch_replica(run, tname, rep)
 
 
@@ -207,8 +209,9 @@ class ReplanRecovery(_DelayedRecovery):
         # the doomed remainder's provisional occupancy must not distort the
         # replan's Eq. (1) estimates — cancel it first
         engine._cancel_provisional(run, tasks=unstarted)
+        tasks = engine.own_placement(run).tasks
         for k in unstarted:
-            del run.placement.tasks[k]
+            del tasks[k]
         with hostspans.span("plan.replan") as replan:
             plan = orchestrate(run.app, cluster, t, engine.policy,
                                pinned=pinned)
@@ -223,7 +226,7 @@ class ReplanRecovery(_DelayedRecovery):
             return
         cluster.apply(plan)
         for k, tp in plan.placement.tasks.items():
-            run.placement.tasks[k] = tp
+            tasks[k] = tp
             run.origins[k] = plan.now
         engine._start_task(run, tname)
 

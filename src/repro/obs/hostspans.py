@@ -103,7 +103,8 @@ HOST_SPAN_SCHEMA: Dict[str, str] = {
     "policy.kernel": "span: one placement kernel's pad, cast, jitted call "
                      "and read-back (attrs: G, rows)",
     "engine.step": "span: one Engine.run(until) (attrs: arrival, task_end, "
-                   "other, launches, talloc_writes and the matching *_ns)",
+                   "device_down, device_up, recover, other, launches, "
+                   "talloc_writes and the matching *_ns)",
     "gc.collect": "record: one pass of the cyclic garbage collector, from "
                   "the gc.callbacks hook, under the innermost open span "
                   "(attrs: generation, collected, uncollectable)",
@@ -112,7 +113,16 @@ HOST_SPAN_SCHEMA: Dict[str, str] = {
     "engine.task_end": "timed counter: TASK_END events, heappop to the "
                        "next pop (retire, later-stage launches)",
     "engine.other": "timed counter: DEVICE_DOWN / DEVICE_UP / RECOVER "
-                    "events, heappop to the next pop",
+                    "events, heappop to the next pop (the sum of the next "
+                    "three)",
+    "engine.device_down": "timed counter: DEVICE_DOWN events, heappop to "
+                          "the next pop (kills, returned occupancy, "
+                          "recovery hooks)",
+    "engine.device_up": "timed counter: DEVICE_UP events, heappop to the "
+                        "next pop (rejoin, cold caches)",
+    "engine.recover": "timed counter: RECOVER events, heappop to the next "
+                      "pop (the recovery: a replan, its apply and the "
+                      "relaunch)",
     "talloc.write": "timed counter: ClusterState.add_interval calls made "
                     "inside Engine.run, over all its callers",
     "gc.pause": "timed counter: the passes of the garbage collector kept "

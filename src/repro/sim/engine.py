@@ -47,7 +47,7 @@ from __future__ import annotations
 import heapq
 import itertools
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -446,6 +446,13 @@ class Engine:
             )
         self._push(t, self.RECOVER, (run, tname, run.epoch))
 
+    def own_placement(self, run: _AppRun) -> Placement:
+        """The run's own copy of its task map, taken before a recovery
+        rewrites it: the plan an arrival brought stays as its caller
+        holds it (the caller of a fused wave may read its plans later)."""
+        run.placement = replace(run.placement, tasks=dict(run.placement.tasks))
+        return run.placement
+
     def _finish_app(self, run: _AppRun, failed: bool) -> None:
         if not np.isnan(run.rec.finished):
             return
@@ -493,12 +500,11 @@ class Engine:
         self._cancel_running(run)
         self._cancel_provisional(run)
         done = {k for k, v in run.done.items() if v}
-        pinned = {
-            k: tp for k, tp in run.placement.tasks.items() if k in done
-        }
-        for k in list(run.placement.tasks):
+        tasks = self.own_placement(run).tasks
+        pinned = {k: tp for k, tp in tasks.items() if k in done}
+        for k in list(tasks):
             if k not in pinned:
-                del run.placement.tasks[k]
+                del tasks[k]
         with hostspans.span("plan.replan") as replan:
             plan = orchestrate(run.app, cluster, t, self.policy, pinned=pinned)
         self.replan_time += replan.ns / 1e9
@@ -576,15 +582,22 @@ class Engine:
             launches = int(self.load.sum())
             with self.cluster.timing_writes() as writes:
                 self._run(until, (ns, n))
+            down, up, rec = self.DEVICE_DOWN, self.DEVICE_UP, self.RECOVER
             step.set(arrival=n[self.ARRIVAL], arrival_ns=ns[self.ARRIVAL],
                      task_end=n[self.TASK_END],
                      task_end_ns=ns[self.TASK_END],
+                     device_down=n[down], device_down_ns=ns[down],
+                     device_up=n[up], device_up_ns=ns[up],
+                     recover=n[rec], recover_ns=ns[rec],
                      other=sum(n[2:]), other_ns=sum(ns[2:]),
                      launches=int(self.load.sum()) - launches,
                      talloc_writes=writes[0], talloc_ns=writes[1])
         hostspans.tally("engine.arrival", n[self.ARRIVAL], ns[self.ARRIVAL])
         hostspans.tally("engine.task_end", n[self.TASK_END],
                         ns[self.TASK_END])
+        hostspans.tally("engine.device_down", n[down], ns[down])
+        hostspans.tally("engine.device_up", n[up], ns[up])
+        hostspans.tally("engine.recover", n[rec], ns[rec])
         hostspans.tally("engine.other", sum(n[2:]), sum(ns[2:]))
         hostspans.tally("talloc.write", writes[0], writes[1])
 

@@ -500,3 +500,44 @@ def test_serving_fleet_churn_replan(profile):
     assert pf_rp <= pf_ff
     if stats_ff["lost"] > 0:
         assert stats_rp["replans"] > 0
+
+
+
+# ------------------------------------------- a recovery owns its placement --
+def _fused_one_task_plans(cluster, n):
+    """``n`` one-task apps planned as one fused wave at t = 0 (all on the
+    fastest device: one snapshot)."""
+    from repro.api import orchestrate_batch
+
+    plans = orchestrate_batch([one_task_app(f"app{i}") for i in range(n)],
+                              cluster, make_policy("lavea"), times=[0.0] * n)
+    assert {p.tasks["t0"].replicas[0].did for p in plans} == {0}
+    return plans
+
+
+@pytest.mark.parametrize("recovery", ("failover", "replan"))
+def test_recovery_leaves_the_arrival_plan_as_planned(recovery):
+    """A recovery rewrites the run's own copy of its task map: the plans
+    a fused wave handed to the engine still say what was planned (the
+    caller may read them after the window), while the runs move to a
+    live device and complete."""
+    cluster = small_cluster(base=[0.3, 0.32, 0.34, 0.36], lam=1e-4)
+    churn = deterministic_churn([(0.1, 0, "leave")])
+    eng = Engine(cluster, make_policy("lavea"), noise_sigma=0.0, churn=churn,
+                 recovery=make_recovery(recovery, detection_delay=0.05))
+    plans = _fused_one_task_plans(cluster, 3)
+    planned = [p.tasks["t0"] for p in plans]
+    runs, real = [], eng.recovery.recover
+
+    def recover(engine, run, tname):
+        runs.append(run)
+        return real(engine, run, tname)
+
+    eng.recovery.recover = recover
+    eng.add_arrivals([p.app for p in plans], [0.0] * 3, plans=plans)
+    eng.run(until=5.0)
+    assert eng.stats["replica_deaths"] == 3 and len(runs) == 3
+    assert [p.tasks["t0"] for p in plans] == planned
+    assert all(tp.replicas[0].did == 0 for tp in planned)
+    assert all(r.placement.tasks["t0"].replicas[0].did != 0 for r in runs)
+    assert all(r.done["t0"] and not r.failed for r in runs)

@@ -136,6 +136,9 @@ HOST_SPAN_NAMES = (
     "engine.arrival",
     "engine.task_end",
     "engine.other",
+    "engine.device_down",
+    "engine.device_up",
+    "engine.recover",
     "talloc.write",
     "gc.pause",
 )
@@ -873,11 +876,18 @@ def test_replan_time_reads_the_replan_spans(profile, traced):
         hostspans.disable()
     replans = hostspans.records("plan.replan")
     other = hostspans.timed("engine.other")
+    down, up, recover = (hostspans.timed(n) for n in (
+        "engine.device_down", "engine.device_up", "engine.recover"))
     hostspans.clear()
     assert len(replans) == orch.stats["replans"] + orch.stats["salvages"]
     assert orch.engine.replan_time == pytest.approx(
         sum(r.ns for r in replans) / 1e9, rel=1e-9)
-    assert other[0] >= orch.stats["device_down"] + orch.stats["device_up"]
+    # engine.other is the sum of the three event kinds it covers
+    assert (down[0] + up[0] + recover[0], down[1] + up[1] + recover[1]) \
+        == other
+    assert down[0] == orch.stats["device_down"]
+    assert up[0] == orch.stats["device_up"]
+    assert recover[0] >= orch.stats["replans"]
 
 
 def test_wave_plan_metrics_read_the_wave_spans(profile, monkeypatch):
